@@ -40,13 +40,10 @@ def main() -> None:
              bench_distributed),
             ("kernels (pallas vs oracle)", bench_kernels),
             ("roofline (dry-run 3-term table)", bench_roofline)]
-    # moe crossover needs 512 placeholder devices; include only when the
-    # process was launched with the dry-run XLA flag
-    import jax
-    if jax.device_count() >= 512:
-        from benchmarks import bench_moe_crossover
-        mods.append(("MoE EP crossover (weight-gathered vs token-routed)",
-                     bench_moe_crossover))
+    # No JAX backend may start in this process before bench_distributed
+    # forks its domain processes: on a TPU host the parent would hold the
+    # chip.  The MoE crossover dry run (512 placeholder devices) is run on
+    # its own: python -m benchmarks.bench_moe_crossover
     for title, mod in mods:
         print(f"\n===== {title} =====")
         try:

@@ -120,7 +120,7 @@ class ModelConfig:
     param_dtype: str = "float32"      # master parameter dtype
     remat: str = "none"               # none | dots | full
     scan_layers: bool = True
-    attn_impl: str = "blockwise"      # reference | blockwise | pallas
+    attn_impl: str = "blockwise"      # reference | blockwise
     moe_impl: str = "ep"              # dense | ep | ep_a2a
     # perf-loop knobs (EXPERIMENTS.md §Perf)
     seq_shard: bool = False           # context parallelism: seq over "model"

@@ -37,6 +37,7 @@ except ImportError:              # pragma: no cover - numpy is a CI dep
     np = None
 
 from repro.core.economy import (AdmissionError, TradeFederation, TradeServer)
+from repro.core.persistence import left_sum
 from repro.core.resources import ResourceDirectory
 from repro.core.simulator import Simulator
 
@@ -561,8 +562,8 @@ class AuctionHouse:
         if any(c.end <= t for c in live):
             live = [c for c in live if c.end > t]
             self._live[user] = live
-        return sum(c.max_commitment(self.federation.directory, t)
-                   for c in live)
+        return left_sum(c.max_commitment(self.federation.directory, t)
+                        for c in live)
 
 
 class AuctionBroker:

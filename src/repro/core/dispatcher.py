@@ -22,6 +22,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
+import traceback
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Dict, Optional, Union
 
@@ -258,7 +259,10 @@ class LocalExecutor:
                 with self._lock:
                     job.slot_held = False
                     st.release()
-                cb.on_failed(job, f"payload raised: {e!r}")
+                # the whole traceback rides in the reason: the journal's
+                # FAIL record is the only place a payload failure survives
+                cb.on_failed(job, f"payload raised: {e!r}\n"
+                                  f"{traceback.format_exc()}")
                 return
             with self._lock:
                 job.slot_held = False

@@ -46,6 +46,7 @@ from repro.core.economy import (PriceSchedule, TradeFederation, TradeServer,
 from repro.core.gis import GridInformationService
 from repro.core.jobs import JobSpec
 from repro.core.parametric import NimrodG
+from repro.core.persistence import left_sum
 from repro.core.resources import (ResourceDirectory, ResourceSpec,
                                   gusto_like_testbed)
 from repro.core.scheduler import SchedulerConfig
@@ -477,7 +478,7 @@ class Marketplace:
         names = self.directory.all_names()
         if not names:
             return 0.0
-        return sum(self.trade.quote(n, t) for n in names) / len(names)
+        return left_sum(self.trade.quote(n, t) for n in names) / len(names)
 
     def _watch(self, sample_interval: float, horizon: float) -> None:
         t = self.sim.now
@@ -587,7 +588,7 @@ class Marketplace:
             seed=self.seed, n_users=len(outcomes),
             n_resources=len(self.directory.all_names()),
             outcomes=outcomes, total_jobs=total_jobs, total_done=total_done,
-            total_spent=sum(o.spent for o in outcomes),
+            total_spent=left_sum(o.spent for o in outcomes),
             slot_races_lost=sum(o.slot_races_lost for o in outcomes),
             deadline_met_frac=met / max(len(outcomes), 1),
             price_trace=list(self.price_trace),

@@ -20,6 +20,19 @@ def stable_dumps(obj: Any) -> str:
     return json.dumps(obj, sort_keys=True)
 
 
+def left_sum(values) -> float:
+    """Plain left-to-right float sum, rounded after every addition.
+
+    Python 3.12's ``sum()`` compensates float rounding, so its result can
+    differ in the last bit from this fold.  Every float total that reaches
+    ``stable_repr``, a journal or a bank total goes through here, so those
+    bytes do not depend on the interpreter version."""
+    total = 0
+    for v in values:
+        total = total + v
+    return total
+
+
 # tail window read when recovering ``seq`` on reopen; grows geometrically
 # if the last well-formed line is longer than this (rare: one event)
 _TAIL_BLOCK = 64 * 1024

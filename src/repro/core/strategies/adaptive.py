@@ -18,6 +18,7 @@ from __future__ import annotations
 
 from typing import Optional, Set
 
+from repro.core.persistence import left_sum
 from repro.core.strategies.base import Strategy, StrategyContext, register
 
 
@@ -46,11 +47,11 @@ class AdaptiveStrategy(Strategy):
         xs = [c.t - t0 for c in hist]
         ys = [c.price for c in hist]
         n = float(len(xs))
-        mx, my = sum(xs) / n, sum(ys) / n
-        var = sum((x - mx) ** 2 for x in xs)
+        mx, my = left_sum(xs) / n, left_sum(ys) / n
+        var = left_sum((x - mx) ** 2 for x in xs)
         if var <= 1e-12:                       # all clearings at one t
             return my
-        slope = sum((x - mx) * (y - my) for x, y in zip(xs, ys)) / var
+        slope = left_sum((x - mx) * (y - my) for x, y in zip(xs, ys)) / var
         pred = my + slope * ((ctx.t - t0) - mx)
         # bound the extrapolation by the observed band: a two-point
         # trend must not predict free (or absurd) capacity

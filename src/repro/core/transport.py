@@ -430,6 +430,10 @@ class DomainProcess:
     def __init__(self, cfg: DomainConfig,
                  ctx: Optional[multiprocessing.context.BaseContext] = None):
         self.cfg = cfg
+        # forked children must stay JAX-free, and so must the parent up
+        # to the fork: on a TPU host a parent that has started a JAX
+        # backend holds the chip, and a child that touches it fails or
+        # hangs.  core/ imports no JAX, which keeps domain processes safe
         self._ctx = ctx or multiprocessing.get_context("fork")
         self._proc: Optional[multiprocessing.Process] = None
         self._conn = None

@@ -88,7 +88,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, scale=None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool = False):
     """q: (B,H,Sq,D), k/v: (B,K,Sk,D). Returns (B,H,Sq,D).
 
     When Sq != Sk the queries are suffix-aligned (query i sits at key

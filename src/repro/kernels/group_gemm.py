@@ -3,12 +3,13 @@ simplified for the capacity-bucketed MoE dispatch.
 
 x: (E, C, D) tokens bucketed per expert, w: (E, D, F) expert weights,
 n_valid: (E,) number of real rows per expert.  Blocks whose rows are
-entirely padding are *skipped at the grid level* (no DMA, no MXU) — with
-load imbalance this saves (1 - load/capacity) of the work, which is the
-dropless-MoE insight mapped onto static TPU grids.
+entirely padding are *skipped at the grid level* (no MXU work) — with
+load imbalance this saves (1 - load/capacity) of the compute, which is
+the dropless-MoE insight mapped onto static TPU grids.
 
 Grid = (E, C/bc, F/bf), D contracted in full per block (expert D is the
-small fine-grained-expert dim).  n_valid is staged through SMEM.
+small fine-grained-expert dim).  n_valid is scalar-prefetched whole into
+SMEM, so each grid step reads its expert's count there.
 """
 from __future__ import annotations
 
@@ -23,7 +24,7 @@ from jax.experimental.pallas import tpu as pltpu
 def _gg_kernel(n_ref, x_ref, w_ref, o_ref, *, block_c: int):
     e = pl.program_id(0)
     ci = pl.program_id(1)
-    n = n_ref[0]
+    n = n_ref[e]
     row0 = ci * block_c
 
     @pl.when(row0 < n)
@@ -42,7 +43,7 @@ def _gg_kernel(n_ref, x_ref, w_ref, o_ref, *, block_c: int):
 
 
 def group_gemm(x, w, n_valid, *, block_c: int = 128, block_f: int = 128,
-               interpret: bool = True):
+               interpret: bool = False):
     """x: (E,C,D) @ w: (E,D,F) with per-expert valid counts -> (E,C,F)."""
     E, C, D = x.shape
     F = w.shape[2]
@@ -59,15 +60,16 @@ def group_gemm(x, w, n_valid, *, block_c: int = 128, block_f: int = 128,
     kernel = functools.partial(_gg_kernel, block_c=bc)
     out = pl.pallas_call(
         kernel,
-        grid=(E, nc, nf),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM,
-                         block_shape=(1,),
-                         index_map=lambda e, ci, fi: (e,)),
-            pl.BlockSpec((1, bc, D), lambda e, ci, fi: (e, ci, 0)),
-            pl.BlockSpec((1, D, bf), lambda e, ci, fi: (e, 0, fi)),
-        ],
-        out_specs=pl.BlockSpec((1, bc, bf), lambda e, ci, fi: (e, ci, fi)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(E, nc, nf),
+            in_specs=[
+                pl.BlockSpec((1, bc, D), lambda e, ci, fi, n: (e, ci, 0)),
+                pl.BlockSpec((1, D, bf), lambda e, ci, fi, n: (e, 0, fi)),
+            ],
+            out_specs=pl.BlockSpec((1, bc, bf),
+                                   lambda e, ci, fi, n: (e, ci, fi)),
+        ),
         out_shape=jax.ShapeDtypeStruct((E, nc * bc, nf * bf), x.dtype),
         interpret=interpret,
     )(n_valid.astype(jnp.int32), x, w)

@@ -25,21 +25,22 @@ def _rglru_kernel(loga_ref, b_ref, h0_ref, o_ref, h_scr, *, block_t: int):
 
     @pl.when(ti == 0)
     def _init():
-        h_scr[...] = h0_ref[0]
-
-    a = jnp.exp(loga_ref[0].astype(jnp.float32))       # (bt, bl)
-    b = b_ref[0].astype(jnp.float32)
+        h_scr[...] = h0_ref[0]                          # (1, bl)
 
     def step(i, h):
-        h = a[i] * h + b[i]
-        o_ref[0, i] = h.astype(o_ref.dtype)
+        # one time row at a time, read and written through the refs: a
+        # dynamic index into a loaded (bt, bl) value does not lower
+        row = pl.ds(i, 1)
+        a = jnp.exp(loga_ref[0, row, :].astype(jnp.float32))
+        h = a * h + b_ref[0, row, :].astype(jnp.float32)
+        o_ref[0, row, :] = h.astype(o_ref.dtype)
         return h
 
     h_scr[...] = jax.lax.fori_loop(0, block_t, step, h_scr[...])
 
 
 def rglru_scan(log_a, b, h0=None, *, block_t: int = 128, block_l: int = 256,
-               interpret: bool = True):
+               interpret: bool = False):
     """log_a, b: (B,S,L) fp32; h0: (B,L) or None -> h (B,S,L) fp32."""
     B, S, L = log_a.shape
     if h0 is None:
@@ -54,6 +55,9 @@ def rglru_scan(log_a, b, h0=None, *, block_t: int = 128, block_l: int = 256,
         log_a = jnp.pad(log_a, ((0, 0), (0, pt), (0, plx)))
         b = jnp.pad(b, ((0, 0), (0, pt), (0, plx)))
         h0 = jnp.pad(h0, ((0, 0), (0, plx)))
+    # h0 rides as (B, 1, L): a (1, bl) block of a (B, L) array breaks the
+    # TPU's (8, 128) tiling rule, a (1, 1, bl) block of (B, 1, L) does not
+    h0 = h0.reshape(B, 1, nl * bl)
 
     kernel = functools.partial(_rglru_kernel, block_t=bt)
     out = pl.pallas_call(
@@ -62,11 +66,11 @@ def rglru_scan(log_a, b, h0=None, *, block_t: int = 128, block_l: int = 256,
         in_specs=[
             pl.BlockSpec((1, bt, bl), lambda bi, li, ti: (bi, ti, li)),
             pl.BlockSpec((1, bt, bl), lambda bi, li, ti: (bi, ti, li)),
-            pl.BlockSpec((1, bl), lambda bi, li, ti: (bi, li)),
+            pl.BlockSpec((1, 1, bl), lambda bi, li, ti: (bi, 0, li)),
         ],
         out_specs=pl.BlockSpec((1, bt, bl), lambda bi, li, ti: (bi, ti, li)),
         out_shape=jax.ShapeDtypeStruct((B, nt * bt, nl * bl), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bl,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, bl), jnp.float32)],
         interpret=interpret,
     )(log_a, b, h0)
     return out[:, :S, :L]

@@ -34,30 +34,36 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sout_ref,
     k = k_ref[0].astype(f32)
     v = v_ref[0].astype(f32)
     lw = lw_ref[0].astype(f32)
-    u = u_ref[0].astype(f32)            # (N,)
+    u = u_ref[0].astype(f32)            # (1,N)
 
-    cum = jnp.cumsum(lw, axis=0)
-    tot = cum[-1]
-    q = r * jnp.exp(cum - lw)
-    kk = k * jnp.exp(-cum)
-    att = jax.lax.dot_general(q, kk, (((1,), (1,)), ((), ())),
-                              preferred_element_type=f32)    # (C,C)
-    C = att.shape[0]
+    def mm(a, b, dims, precision=None):
+        return jax.lax.dot_general(a, b, (dims, ((), ())),
+                                   precision=precision,
+                                   preferred_element_type=f32)
+
+    C = lw.shape[0]
     ti_i = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     si_i = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    # prefix sums over time as matmuls (Mosaic has no cumsum): the
+    # inclusive one by a lower-triangular ones matrix, the chunk total
+    # as a column by contracting time against ones.  HIGHEST keeps them
+    # exact sums of f32 decays, which the exponentials below amplify
+    exact = jax.lax.Precision.HIGHEST
+    cum = mm((si_i <= ti_i).astype(f32), lw, ((1,), (0,)), exact)  # (C,N)
+    tot = cum[C - 1:]                                               # (1,N)
+    tot_col = mm(lw, jnp.ones((C, 1), f32), ((0,), (0,)), exact)   # (N,1)
+    q = r * jnp.exp(cum - lw)
+    kk = k * jnp.exp(-cum)
+    att = mm(q, kk, ((1,), (1,)))                            # (C,C)
     att = jnp.where(si_i < ti_i, att, 0.0)
-    y = jax.lax.dot_general(att, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=f32)
-    diag = jnp.sum(r * u[None, :] * k, axis=1)               # (C,)
-    y = y + diag[:, None] * v
-    y = y + jax.lax.dot_general(q, s_scr[...], (((1,), (0,)), ((), ())),
-                                preferred_element_type=f32)
+    y = mm(att, v, ((1,), (0,)))
+    diag = jnp.sum(r * u * k, axis=1, keepdims=True)         # (C,1)
+    y = y + diag * v
+    y = y + mm(q, s_scr[...], ((1,), (0,)))
     y_ref[0] = y.astype(y_ref.dtype)
 
-    kw = k * jnp.exp(tot[None, :] - cum)
-    s_scr[...] = jnp.exp(tot)[:, None] * s_scr[...] + \
-        jax.lax.dot_general(kw, v, (((0,), (0,)), ((), ())),
-                            preferred_element_type=f32)
+    kw = k * jnp.exp(tot - cum)
+    s_scr[...] = jnp.exp(tot_col) * s_scr[...] + mm(kw, v, ((0,), (0,)))
 
     @pl.when(ti == num_t - 1)
     def _finish():
@@ -65,7 +71,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sout_ref,
 
 
 def wkv(r, k, v, logw, u, state0=None, *, chunk: int = 32,
-        interpret: bool = True):
+        interpret: bool = False):
     """r,k,v,logw: (B,S,H,N); u: (H,N); state0: (B,H,N,N) or None.
 
     Returns (y (B,S,H,N), state (B,H,N,N)).  S is padded to a chunk
@@ -85,7 +91,9 @@ def wkv(r, k, v, logw, u, state0=None, *, chunk: int = 32,
 
     rf, kf, vf = prep(r), prep(k), prep(v)
     lwf = prep(logw)
-    uf = jnp.broadcast_to(u[None], (B, H, N)).reshape(B * H, N)
+    # u rides as (B*H, 1, N): a (1, N) block of a (B*H, N) array breaks
+    # the TPU's (8, 128) tiling rule, a (1, 1, N) block does not
+    uf = jnp.broadcast_to(u[None], (B, H, N)).reshape(B * H, 1, N)
     s0 = (jnp.zeros((B * H, N, N), jnp.float32) if state0 is None
           else state0.reshape(B * H, N, N))
 
@@ -98,7 +106,7 @@ def wkv(r, k, v, logw, u, state0=None, *, chunk: int = 32,
             pl.BlockSpec((1, C, N), lambda h, ti: (h, ti, 0)),
             pl.BlockSpec((1, C, N), lambda h, ti: (h, ti, 0)),
             pl.BlockSpec((1, C, N), lambda h, ti: (h, ti, 0)),
-            pl.BlockSpec((1, N), lambda h, ti: (h, 0)),
+            pl.BlockSpec((1, 1, N), lambda h, ti: (h, 0, 0)),
             pl.BlockSpec((1, N, N), lambda h, ti: (h, 0, 0)),
         ],
         out_specs=[

@@ -4,27 +4,16 @@
 device state).  Single pod = 16x16 = 256 chips over ("data", "model");
 multi-pod = 2x16x16 = 512 chips with a leading pure-DP "pod" axis whose
 gradient all-reduce is the only traffic crossing the pod boundary.
-
-``AxisType`` only exists on newer JAX (>= 0.5); on older installs we
-simply omit ``axis_types`` — every mesh here is fully Auto anyway, which
-is also the old default.
+Every mesh here is fully ``Auto``.
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # older jax: no explicit axis types, Auto is implicit
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

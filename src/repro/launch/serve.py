@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import transformer as tfm
 from repro.train.steps import make_decode_step, make_prefill_step
@@ -92,6 +93,7 @@ def main(argv=None) -> int:
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args(argv)
+    use_compile_cache()
     serve_batch(args.arch, smoke=not args.full, batch=args.batch,
                 prompt_len=args.prompt_len, gen=args.gen)
     return 0

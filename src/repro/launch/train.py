@@ -23,6 +23,7 @@ import numpy as np
 from repro.checkpoint import latest_step_dir, load_metadata, restore, save
 from repro.configs import get_config, smoke_config
 from repro.data import DataConfig, SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import transformer as tfm
 from repro.optim import AdamWConfig, abstract_opt_state, init_opt_state
@@ -34,8 +35,10 @@ class TrainResult:
     steps: int
     final_loss: float
     losses: list
-    tokens_per_sec: float
+    tokens_per_sec: float             # over the timed steps, compile excluded
     restored_from: Optional[str] = None
+    compile_seconds: float = 0.0      # lower + compile of the step
+    step_seconds: list = dataclasses.field(default_factory=list)
 
 
 def run_training(arch: str, *, smoke: bool = True, steps: int = 50,
@@ -70,15 +73,27 @@ def run_training(arch: str, *, smoke: bool = True, steps: int = 50,
         params = tfm.init_model(cfg, jax.random.PRNGKey(seed))
         opt_state = init_opt_state(params, opt_cfg)
 
+    # params and optimizer state are donated: the update writes into the
+    # old buffers instead of holding two copies of both across a step
     step_fn = jax.jit(make_train_step(cfg, opt_cfg, mesh=mesh,
-                                      total_steps=max(steps, 100)))
+                                      total_steps=max(steps, 100)),
+                      donate_argnums=(0, 1))
+    compiled = None
+    compile_seconds = 0.0
     losses = []
-    t0 = time.time()
+    step_seconds = []
     tokens = 0
     for step in range(start_step, steps):
         b = data.batch(step)
         batch_dev = {k: jax.numpy.asarray(v) for k, v in b.items()}
-        params, opt_state, metrics = step_fn(params, opt_state, batch_dev)
+        if compiled is None:
+            t0 = time.perf_counter()
+            compiled = step_fn.lower(params, opt_state, batch_dev).compile()
+            compile_seconds = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        params, opt_state, metrics = compiled(params, opt_state, batch_dev)
+        jax.block_until_ready((params, opt_state, metrics))
+        step_seconds.append(time.perf_counter() - t0)
         loss = float(metrics["loss"])
         losses.append(loss)
         tokens += batch * seq
@@ -98,12 +113,13 @@ def run_training(arch: str, *, smoke: bool = True, steps: int = 50,
                            "entries": [], "crcs": {}}, f)
             if verbose:
                 print(f"checkpointed -> {d}")
-    dt = max(time.time() - t0, 1e-9)
     return TrainResult(steps=steps - start_step,
                        final_loss=losses[-1] if losses else float("nan"),
                        losses=losses,
-                       tokens_per_sec=tokens / dt,
-                       restored_from=restored_from)
+                       tokens_per_sec=tokens / max(sum(step_seconds), 1e-9),
+                       restored_from=restored_from,
+                       compile_seconds=compile_seconds,
+                       step_seconds=step_seconds)
 
 
 def main(argv=None) -> int:
@@ -120,6 +136,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--quantized-moments", action="store_true")
     args = ap.parse_args(argv)
+    use_compile_cache()
     r = run_training(args.arch, smoke=args.smoke, steps=args.steps,
                      batch=args.batch, seq=args.seq, lr=args.lr,
                      ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,

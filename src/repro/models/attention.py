@@ -1,12 +1,14 @@
 """Attention layers: full/global, sliding-window local, GQA, decode paths.
 
-Three implementations share one math definition (``ref`` in
+Two implementations share one math definition (``ref`` in
 ``repro.kernels.ref`` mirrors these):
 
 * ``reference`` — plain einsum + mask; O(S^2) materialized (small S only).
 * ``blockwise`` — lax.scan over KV blocks with online softmax; flash-style
   peak memory, used for long sequences and as the dry-run lowering path.
-* ``pallas``    — TPU kernel (``repro.kernels``); selected on TPU backends.
+
+The Pallas flash-attention kernel (``repro.kernels.flash_attention``)
+implements the same math, but no layer here calls it.
 
 Local (sliding-window) attention uses an exact two-chunk banded layout so
 FLOPs scale with S*W, not S^2.

@@ -29,11 +29,6 @@ from repro.configs.base import ModelConfig
 from repro.models.common import ParamSpec
 from repro.models.mlp import mlp_specs, mlp_apply
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
 
 def moe_specs(cfg: ModelConfig) -> dict:
     mo = cfg.moe
@@ -199,7 +194,7 @@ def moe_ep(cfg: ModelConfig, p: dict, x, *, mesh, train: bool):
     body = functools.partial(_ep_local, cfg, capacity, n_model, batch_axes,
                              n_batch)
     tspec = P(batch_axes if batch_axes else None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tspec, P(None, None), P("model", "data", None),
                   P("model", "data", None), P("model", None, "data")),
@@ -327,7 +322,7 @@ def moe_a2a(cfg: ModelConfig, p: dict, x, *, mesh, train: bool):
     body = functools.partial(_a2a_local, cfg, cap_out, cap_exp, n_data,
                              n_model, batch_axes, n_batch)
     tspec = P(batch_axes if batch_axes else None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tspec, P(None, None), P("data", None, "model"),
                   P("data", None, "model"), P("data", "model", None)),

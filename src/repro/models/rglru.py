@@ -9,8 +9,9 @@ RG-LRU cell (all elementwise over the lru width):
     h_t = a_t * h_{t-1} + sqrt(1 - a_t^2) * (i_t * x_t)
 
 Training/prefill lower to ``lax.associative_scan`` (log-depth, parallel);
-decode is a single fused step.  The Pallas TPU kernel lives in
-``repro.kernels.rglru_scan``.
+decode is a single fused step.  The Pallas TPU kernel in
+``repro.kernels.rglru_scan`` computes the same scan, but this block does
+not call it.
 """
 from __future__ import annotations
 

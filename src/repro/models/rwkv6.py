@@ -10,7 +10,8 @@ LoRA, and the ddlerp token-shift data-dependent interpolation.
 
 Training lowers to a chunked scan (chunk=64) — parallel within chunks,
 sequential across chunk states; decode is a single state update.  The
-Pallas TPU kernel lives in ``repro.kernels.rwkv_wkv``.
+Pallas TPU kernel in ``repro.kernels.rwkv_wkv`` computes the same WKV, but
+this block does not call it.
 """
 from __future__ import annotations
 

@@ -31,7 +31,7 @@ def test_flash_attention_shapes_dtypes(B, H, K, Sq, Sk, D, dtype):
     q = jax.random.normal(ks[0], (B, H, Sq, D), dtype)
     k = jax.random.normal(ks[1], (B, K, Sk, D), dtype)
     v = jax.random.normal(ks[2], (B, K, Sk, D), dtype)
-    out = ops.flash_attention(q, k, v, block_q=64, block_k=64)
+    out = ops.flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
     want = ref.attention_ref(q, k, v)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32),
@@ -45,7 +45,8 @@ def test_flash_attention_sliding_window(window):
     q = jax.random.normal(ks[0], (B, H, S, D))
     k = jax.random.normal(ks[1], (B, H, S, D))
     v = jax.random.normal(ks[2], (B, H, S, D))
-    out = ops.flash_attention(q, k, v, window=window, block_q=32, block_k=32)
+    out = ops.flash_attention(q, k, v, window=window, block_q=32, block_k=32,
+                              interpret=True)
     want = ref.attention_ref(q, k, v, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5,
                                rtol=2e-5)
@@ -57,7 +58,8 @@ def test_flash_attention_softcap():
     q = jax.random.normal(ks[0], (B, H, S, D)) * 3
     k = jax.random.normal(ks[1], (B, H, S, D)) * 3
     v = jax.random.normal(ks[2], (B, H, S, D))
-    out = ops.flash_attention(q, k, v, softcap=30.0, block_q=64, block_k=64)
+    out = ops.flash_attention(q, k, v, softcap=30.0, block_q=64, block_k=64,
+                              interpret=True)
     want = ref.attention_ref(q, k, v, softcap=30.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5,
                                rtol=2e-5)
@@ -78,7 +80,7 @@ def test_rglru_scan(B, S, L, bt, bl):
     log_a = -jnp.exp(jax.random.normal(ks[0], (B, S, L)) * 0.5 - 2)
     b = jax.random.normal(ks[1], (B, S, L))
     h0 = jax.random.normal(ks[2], (B, L))
-    out = ops.rglru_scan(log_a, b, h0, block_t=bt, block_l=bl)
+    out = ops.rglru_scan(log_a, b, h0, block_t=bt, block_l=bl, interpret=True)
     want = ref.rglru_ref(log_a, b, h0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5,
                                rtol=1e-5)
@@ -89,7 +91,7 @@ def test_rglru_scan_no_initial_state():
     ks = jax.random.split(KEY, 2)
     log_a = -jnp.exp(jax.random.normal(ks[0], (B, S, L)) * 0.3 - 2)
     b = jax.random.normal(ks[1], (B, S, L))
-    out = ops.rglru_scan(log_a, b, None, block_t=8, block_l=8)
+    out = ops.rglru_scan(log_a, b, None, block_t=8, block_l=8, interpret=True)
     want = ref.rglru_ref(log_a, b, None)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5,
                                rtol=1e-5)
@@ -113,7 +115,7 @@ def test_wkv_chunked_kernel(B, S, H, N, C):
     logw = -jnp.exp(jax.random.normal(ks[3], (B, S, H, N)) * 0.5 - 1.5)
     u = jax.random.normal(ks[4], (H, N)) * 0.5
     s0 = jax.random.normal(ks[5], (B, H, N, N)) * 0.1
-    y, st = ops.wkv(r, k, v, logw, u, s0, chunk=C)
+    y, st = ops.wkv(r, k, v, logw, u, s0, chunk=C, interpret=True)
     yw, stw = ref.wkv_ref(r, k, v, logw, u, s0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yw), atol=5e-4,
                                rtol=5e-4)
@@ -154,7 +156,7 @@ def test_group_gemm(E, C, D, F, dtype):
     x = jax.random.normal(ks[0], (E, C, D), dtype)
     w = jax.random.normal(ks[1], (E, D, F), dtype)
     n = jax.random.randint(ks[2], (E,), 0, C + 1)
-    out = ops.group_gemm(x, w, n)
+    out = ops.group_gemm(x, w, n, interpret=True)
     want = ref.group_gemm_ref(x, w, n)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
@@ -166,7 +168,7 @@ def test_group_gemm_zero_valid_rows():
     x = jnp.ones((E, C, D))
     w = jnp.ones((E, D, F))
     n = jnp.array([0, 16, 5])
-    out = np.asarray(ops.group_gemm(x, w, n))
+    out = np.asarray(ops.group_gemm(x, w, n, interpret=True))
     assert (out[0] == 0).all()
     assert (out[1] != 0).all()
     assert (out[2, 5:] == 0).all() and (out[2, :5] != 0).all()

@@ -175,3 +175,19 @@ def test_recover_tail_skips_lines_without_int_seq(tmp_path):
     with Journal(p) as j:
         ev = j.append("NEXT")
     assert ev["seq"] == 8                          # last line WITH a seq
+
+
+@pytest.mark.parametrize("values,want", [
+    ([1e16, 1.0, -1e16], 0.0),          # 1.0 is absorbed, then cancelled
+    ([0.1] * 10, 0.9999999999999999),   # each addition rounds
+    ([], 0),
+])
+def test_left_sum_rounds_after_every_addition(values, want):
+    from repro.core.persistence import left_sum
+    got = left_sum(values)
+    assert got == want and type(got) is type(want)
+    # a plain fold: the order of the additions is the order of the input
+    acc = 0
+    for v in values:
+        acc += v
+    assert got == acc

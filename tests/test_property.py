@@ -239,7 +239,7 @@ def test_flash_attention_random(B, G, S, D, causal, win_mode, seed):
     k = jax.random.normal(ks[1], (B, K, S, D))
     v = jax.random.normal(ks[2], (B, K, S, D))
     out = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              block_q=32, block_k=32)
+                              block_q=32, block_k=32, interpret=True)
     want = ref.attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=3e-5, rtol=3e-5)
@@ -254,7 +254,7 @@ def test_rglru_random(B, S, L, seed):
     log_a = -jnp.exp(jax.random.normal(ks[0], (B, S, L)) * 0.5 - 2)
     b = jax.random.normal(ks[1], (B, S, L))
     h0 = jax.random.normal(ks[2], (B, L))
-    out = ops.rglru_scan(log_a, b, h0, block_t=16, block_l=16)
+    out = ops.rglru_scan(log_a, b, h0, block_t=16, block_l=16, interpret=True)
     want = ref.rglru_ref(log_a, b, h0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
