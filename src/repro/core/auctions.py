@@ -366,8 +366,6 @@ class AuctionHouse:
         m.gauge("auction.rounds", fn=lambda: float(len(self.rounds)))
         m.gauge("auction.contracts",
                 fn=lambda: float(len(self.contracts)))
-        m.gauge("auction.matched_slots",
-                fn=lambda: float(sum(r.matched_slots for r in self.rounds)))
 
     # -- wiring --------------------------------------------------------
     def register(self, user: str,

@@ -552,7 +552,7 @@ class Marketplace:
         if self.tracer is not None:
             m = self.tracer.metrics
             m.gauge("market.sim_events").set(float(self.sim.events))
-            # final registry snapshot BEFORE the wall-derived gauges are
+            # final registry snapshot BEFORE the wall-derived gauge is
             # registered: everything in the event stream (and hence the
             # JSONL export) stays deterministic; throughput lands only
             # in the registry, i.e. the Chrome export's otherData
@@ -560,7 +560,6 @@ class Marketplace:
             wall = max(time.perf_counter() - wall0, 1e-9)
             m.gauge("market.events_per_sec", unit="ev/s").set(
                 self.sim.events / wall)
-            m.gauge("market.wall_seconds", unit="s").set(wall)
         return self._report()
 
     # ------------------------------------------------------------------

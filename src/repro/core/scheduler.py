@@ -169,15 +169,14 @@ class ScheduleAdvisor:
         self._lv = None
 
     def bind_telemetry(self, tracer, track: str) -> None:
-        """Attach a ``repro.core.telemetry.Tracer``: ``decide`` counts
-        every re-plan and emits a ``sched``/``replan`` instant whenever
-        the allocation actually changed.  Purely observational — the
-        decision is computed identically with or without it."""
+        """Attach a ``repro.core.telemetry.Tracer``: whenever the
+        allocation actually changes, ``decide`` counts it
+        (``sched.replans``) and emits a ``sched``/``replan`` instant.
+        Purely observational — the decision is computed identically
+        with or without it."""
         self._trace = tracer
         self._track = track
-        m = tracer.metrics
-        self._m_decisions = m.counter("sched.decisions")
-        self._m_replans = m.counter("sched.replans")
+        self._m_replans = tracer.metrics.counter("sched.replans")
 
     def bind_market(self, *, secondary=None, bank=None, history=None,
                     gis_client=None) -> None:
@@ -194,11 +193,8 @@ class ScheduleAdvisor:
         """Swap the user's requirements mid-run — the paper's steering
         interaction (deadline/budget can change at any time).  The next
         ``decide`` re-plans against the new deadline; nothing else is
-        cached off the old object.  Counted when telemetry is bound so
-        a steered run's re-planning pressure is visible in the trace."""
+        cached off the old object."""
         self.req = requirements
-        if self._trace is not None:
-            self._trace.metrics.counter("sched.retargets").inc()
 
     # -- selection strategies ------------------------------------------------
 
@@ -294,8 +290,6 @@ class ScheduleAdvisor:
             self._rank_held = set(held)
             self._rank_list = ranked
         if not ranked:   # transient: everything down/suspected — hold state
-            if self._trace is not None:
-                self._m_decisions.inc()
             return AllocationDecision(
                 allocate=[], release=[], projected_rate=0.0,
                 needed_rate=needed, projected_cost_per_job=math.inf,
@@ -349,17 +343,16 @@ class ScheduleAdvisor:
             feasible_time=rate + 1e-12 >= remaining_jobs / time_left,
             feasible_budget=(wcost * remaining_jobs <= ledger.remaining + 1e-9),
         )
-        if self._trace is not None:
-            self._m_decisions.inc()
-            if decision.allocate or decision.release:
-                self._m_replans.inc()
-                self._trace.instant(
-                    t, self._track, "sched", "replan",
-                    allocate=",".join(decision.allocate),
-                    release=",".join(decision.release),
-                    projected_rate=rate, needed_rate=needed,
-                    cost_per_job=(wcost if math.isfinite(wcost) else -1.0),
-                    remaining=remaining_jobs)
+        if self._trace is not None and (decision.allocate
+                                        or decision.release):
+            self._m_replans.inc()
+            self._trace.instant(
+                t, self._track, "sched", "replan",
+                allocate=",".join(decision.allocate),
+                release=",".join(decision.release),
+                projected_rate=rate, needed_rate=needed,
+                cost_per_job=(wcost if math.isfinite(wcost) else -1.0),
+                remaining=remaining_jobs)
         return decision
 
     # -- per-dispatch budget guard -------------------------------------------
