@@ -80,6 +80,7 @@ endtask
                 raise
             return {"lr": point["lr"], "losses": r.losses,
                     "compile_seconds": r.compile_seconds,
+                    "step_reused": r.step_reused,
                     "step_seconds": r.step_seconds}
         return run
 
@@ -178,6 +179,7 @@ def main() -> int:
         steady = statistics.median(r["step_seconds"][1:])
         print(f"{job.job_id} lr={r['lr']}: {job.status.name} "
               f"compile_s={r['compile_seconds']!r} "
+              f"step_reused={r['step_reused']} "
               f"first_step_s={r['step_seconds'][0]!r} "
               f"steady_step_s={steady!r} "
               f"tokens_per_s={BATCH * SEQ / steady!r}")

@@ -8,22 +8,38 @@ schedules and restarts.
     PYTHONPATH=src python -m repro.launch.train --arch gemma3-1b --smoke \
         --steps 50 --batch 8 --seq 256 --ckpt-dir /tmp/run1 --ckpt-every 20
 
+The compiled train step is kept per process and reused across calls: a
+parametric sweep's jobs differ in seed and learning rate, and neither
+changes the program.  The step takes the learning rate as a float32
+argument, ``(params, opt_state, batch, lr)``, and is memoised (a small
+LRU) under a key of what does change the program: the step builder
+(``make_train_step`` as this module holds it at call time), the model
+config, the optimizer config less its ``lr``, the mesh, the schedule's
+``total_steps`` and ``warmup``, and the tree structure and each leaf's
+shape, dtype and sharding of the params, the optimizer state and the
+first batch.  A call whose key is held runs the stored executable: no
+trace, no lowering, no compile or cache load.  ``clear_step_cache``
+empties the memo; ``step_cache_counts`` reads its hits and misses.
+
 ``run_training`` marks its phases as host spans on the profiler's
 timeline (``jax.profiler.TraceAnnotation``, a no-op with no profiler
 running), each on the calling thread and each also timed into the
 ``TrainResult``:
 
 - ``train.init``: entry to the first batch (configuration, mesh, data
-  stream, eager initialisation or restore of the state, the ``jit``
-  wrapper), host time only;
-- ``train.trace``, ``train.lower``, ``train.backend_compile``: the step
-  traced to a jaxpr, lowered to StableHLO, and compiled by XLA or loaded
-  from the persistent compilation cache, once per call;
+  stream, initialisation or restore of the state, waited for on the
+  device);
+- ``train.lookup``: after the first batch, the memo's key computed and
+  looked up;
+- ``train.trace``, ``train.lower``, ``train.backend_compile``: on a miss
+  only, the step traced to a jaxpr, lowered to StableHLO, and compiled by
+  XLA or loaded from the persistent compilation cache;
 - ``train.batch``: each step's host batch and its transfer;
 - ``train.step``: each step's compiled call up to ``block_until_ready``.
 
-It also counts, on the calling thread, the programs it hands to XLA
-through the persistent compilation cache and the cache's hits.
+``TrainResult.step_reused`` says whether the call found its step in the
+memo.  It also counts, on the calling thread, the programs it hands to
+XLA through the persistent compilation cache and the cache's hits.
 """
 from __future__ import annotations
 
@@ -66,6 +82,8 @@ class TrainResult:
     batch_seconds: list = dataclasses.field(default_factory=list)
     cache_requests: int = 0           # programs handed to the persistent
     cache_hits: int = 0               # cache, and those it held
+    lookup_seconds: float = 0.0       # the step memo's key and lookup
+    step_reused: bool = False         # the step came from the memo
 
 
 # JAX's persistent-cache events, counted per thread: the payloads of a
@@ -112,6 +130,113 @@ def _span(name: str, spans: Dict[str, list]):
         spans[name].append(time.perf_counter() - t0)
 
 
+class _StepMemo:
+    """Compiled train steps by what makes the program, the least recently
+    used dropped past ``size``.  Misses compile one at a time, so threads
+    after one step compile it once."""
+
+    def __init__(self, size: int):
+        self.size = size
+        self._steps: "collections.OrderedDict[tuple, Any]" = (
+            collections.OrderedDict())
+        self._lock = threading.Lock()          # the table and the counts
+        self._compile_lock = threading.Lock()  # the miss path
+        self._hits = self._misses = 0
+
+    def get(self, key: tuple):
+        """The compiled step under ``key``, counted as a hit, or None."""
+        with self._lock:
+            compiled = self._steps.get(key)
+            if compiled is not None:
+                self._steps.move_to_end(key)
+                self._hits += 1
+            return compiled
+
+    def compile_once(self, key: tuple, compile_step) -> Tuple[Any, bool]:
+        """After a miss: the step under ``key`` and whether it was found,
+        compiled by ``compile_step`` unless a thread that held the lock
+        first compiled it."""
+        with self._compile_lock:
+            compiled = self.get(key)
+            if compiled is not None:
+                return compiled, True
+            compiled = compile_step()
+            with self._lock:
+                self._misses += 1
+                self._steps[key] = compiled
+                while len(self._steps) > self.size:
+                    self._steps.popitem(last=False)
+            return compiled, False
+
+    def counts(self) -> Tuple[int, int]:
+        with self._lock:
+            return self._hits, self._misses
+
+    def clear(self) -> None:
+        with self._lock:
+            self._steps.clear()
+
+
+# each held step keeps its compiled code on the device: 41 MB for a
+# 12-layer StableLM-2-1.6B step on a TPU v5e
+_step_memo = _StepMemo(size=8)
+
+
+def step_cache_counts() -> Tuple[int, int]:
+    """The step memo's hits and misses so far, over every thread."""
+    return _step_memo.counts()
+
+
+def clear_step_cache() -> None:
+    """Forget every compiled step; the counts keep running."""
+    _step_memo.clear()
+
+
+def _signature(tree) -> tuple:
+    leaves, treedef = jax.tree.flatten(tree)
+    return treedef, tuple((x.shape, x.dtype, x.weak_type, x.sharding)
+                          for x in leaves)
+
+
+def _lr_step(builder, cfg, opt_cfg, mesh, total_steps: int, warmup: int):
+    """The builder's step with the learning rate as its last argument.
+    params and optimizer state are donated: the update writes into the
+    old buffers instead of holding two copies of both across a step.  The
+    compiled module keeps the name ``jit_train_step``, by which a device
+    trace finds the steps."""
+    def train_step(params, opt_state, batch, lr):
+        return builder(cfg, dataclasses.replace(opt_cfg, lr=lr), mesh=mesh,
+                       total_steps=total_steps, warmup=warmup)(
+                           params, opt_state, batch)
+    return jax.jit(train_step, donate_argnums=(0, 1))
+
+
+def _compiled_step(cfg, opt_cfg, mesh, total_steps: int, args: tuple,
+                   spans: Dict[str, list]) -> Tuple[Any, bool]:
+    """The compiled step for ``args`` (params, opt_state, batch, lr) and
+    whether it came from the memo."""
+    warmup = 100
+    with _span("train.lookup", spans):
+        # the builder as this module holds it now, so that a replaced
+        # one is a different program
+        builder = make_train_step
+        key = (builder, cfg, dataclasses.replace(opt_cfg, lr=None), mesh,
+               total_steps, warmup, _signature(args[:3]))
+        compiled = _step_memo.get(key)
+    if compiled is not None:
+        return compiled, True
+
+    def compile_step():
+        step_fn = _lr_step(builder, cfg, opt_cfg, mesh, total_steps, warmup)
+        with _span("train.trace", spans):
+            traced = step_fn.trace(*args)
+        with _span("train.lower", spans):
+            lowered = traced.lower()
+        with _span("train.backend_compile", spans):
+            return lowered.compile()
+    return _step_memo.compile_once(key, compile_step)
+
+
 def run_training(arch: str, *, smoke: bool = True, steps: int = 50,
                  batch: int = 8, seq: int = 256, lr: float = 1e-3,
                  ckpt_dir: Optional[str] = None, ckpt_every: int = 0,
@@ -146,14 +271,12 @@ def run_training(arch: str, *, smoke: bool = True, steps: int = 50,
         if params is None:
             params = tfm.init_model(cfg, jax.random.PRNGKey(seed))
             opt_state = init_opt_state(params, opt_cfg)
+        lr_arg = jax.device_put(np.float32(lr))
+        # the eager initialisation runs here, not under the first step
+        jax.block_until_ready((params, opt_state, lr_arg))
 
-        # params and optimizer state are donated: the update writes into
-        # the old buffers instead of holding two copies of both across a
-        # step
-        step_fn = jax.jit(make_train_step(cfg, opt_cfg, mesh=mesh,
-                                          total_steps=max(steps, 100)),
-                          donate_argnums=(0, 1))
     compiled = None
+    step_reused = False
     losses = []
     tokens = 0
     for step in range(start_step, steps):
@@ -161,15 +284,12 @@ def run_training(arch: str, *, smoke: bool = True, steps: int = 50,
             b = data.batch(step)
             batch_dev = {k: jax.numpy.asarray(v) for k, v in b.items()}
         if compiled is None:
-            with _span("train.trace", spans):
-                traced = step_fn.trace(params, opt_state, batch_dev)
-            with _span("train.lower", spans):
-                lowered = traced.lower()
-            with _span("train.backend_compile", spans):
-                compiled = lowered.compile()
+            compiled, step_reused = _compiled_step(
+                cfg, opt_cfg, mesh, max(steps, 100),
+                (params, opt_state, batch_dev, lr_arg), spans)
         with _span("train.step", spans):
             params, opt_state, metrics = compiled(params, opt_state,
-                                                  batch_dev)
+                                                  batch_dev, lr_arg)
             jax.block_until_ready((params, opt_state, metrics))
         loss = float(metrics["loss"])
         losses.append(loss)
@@ -192,7 +312,7 @@ def run_training(arch: str, *, smoke: bool = True, steps: int = 50,
                 print(f"checkpointed -> {d}")
     requests, hits = _cache_counts()
     trace_s, lower_s, backend_s = (
-        sum(spans[n]) for n in ("train.trace", "train.lower",
+        sum(spans[n], 0.0) for n in ("train.trace", "train.lower",
                                 "train.backend_compile"))
     step_seconds = spans["train.step"]
     return TrainResult(steps=steps - start_step,
@@ -208,7 +328,9 @@ def run_training(arch: str, *, smoke: bool = True, steps: int = 50,
                        init_seconds=sum(spans["train.init"]),
                        batch_seconds=spans["train.batch"],
                        cache_requests=requests - requests0,
-                       cache_hits=hits - hits0)
+                       cache_hits=hits - hits0,
+                       lookup_seconds=sum(spans["train.lookup"]),
+                       step_reused=step_reused)
 
 
 def main(argv=None) -> int:

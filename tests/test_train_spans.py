@@ -1,5 +1,6 @@
 """``run_training``'s host spans on the profiler's timeline, the times it
-reports beside them, and its per-thread persistent-cache counters."""
+reports beside them, its per-thread persistent-cache counters, and its
+step memo as the spans and counters see it."""
 import glob
 import os
 import sys
@@ -14,17 +15,26 @@ from repro.launch import train
 
 ARCH = "stablelm-1.6b"
 TINY = dict(smoke=True, steps=2, batch=2, seq=32, verbose=False)
-# what a run of two steps writes, in order, on the thread that called it
-SPANS = ["train.init", "train.batch", "train.trace", "train.lower",
-         "train.backend_compile", "train.step", "train.batch", "train.step"]
+# what a run of two steps writes, in order, on the thread that called it:
+# with its step compiled, and with its step found in the memo
+SPANS = ["train.init", "train.batch", "train.lookup", "train.trace",
+         "train.lower", "train.backend_compile", "train.step",
+         "train.batch", "train.step"]
+SPANS_REUSED = ["train.init", "train.batch", "train.lookup", "train.step",
+                "train.batch", "train.step"]
 
 
 @pytest.fixture(scope="module")
-def traced(tmp_path_factory):
-    """A tiny run under the profiler; its result and its ``train.*``
-    host events, by the thread (line) that wrote them."""
+def no_memo():
+    """An empty step memo, whatever ran before in this process: the next
+    call compiles its step."""
+    train.clear_step_cache()
+
+
+def _traced_run(log_dir):
+    """A tiny run under the profiler; its result and its ``train.*`` host
+    events, by the thread (line) that wrote them."""
     from jax.profiler import ProfileData
-    log_dir = str(tmp_path_factory.mktemp("trace"))
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(log_dir, profiler_options=opts)
@@ -47,28 +57,52 @@ def traced(tmp_path_factory):
     return result, lines
 
 
-def test_spans_on_one_thread_in_order(traced):
-    _, lines = traced
+@pytest.fixture(scope="module")
+def traced(no_memo, tmp_path_factory):
+    return _traced_run(str(tmp_path_factory.mktemp("trace")))
+
+
+@pytest.fixture(scope="module")
+def traced_reused(traced, tmp_path_factory):
+    """The same run again: its step is the memo's."""
+    return _traced_run(str(tmp_path_factory.mktemp("trace_reused")))
+
+
+def _assert_spans(lines, names):
     assert len(lines) == 1
     events, = lines.values()
-    assert [name for name, _, _ in events] == SPANS
+    assert [name for name, _, _ in events] == names
     # each span ends before the next begins: none nests in another
     for (_, _, end), (_, start, _) in zip(events, events[1:]):
         assert end <= start
+
+
+def test_spans_on_one_thread_in_order(traced):
+    result, lines = traced
+    assert not result.step_reused
+    _assert_spans(lines, SPANS)
+
+
+def test_reused_step_writes_no_compile_span(traced_reused):
+    result, lines = traced_reused
+    assert result.step_reused
+    assert result.compile_seconds == 0.0
+    _assert_spans(lines, SPANS_REUSED)
 
 
 def test_span_times_in_the_result(traced):
     r, lines = traced
     assert r.trace_seconds > 0 and r.lower_seconds > 0
     assert r.backend_compile_seconds > 0 and r.init_seconds > 0
+    assert r.lookup_seconds > 0
     assert r.compile_seconds == pytest.approx(
         r.trace_seconds + r.lower_seconds + r.backend_compile_seconds,
         rel=1e-12)
     assert len(r.batch_seconds) == len(r.step_seconds) == TINY["steps"]
     # the perf_counter clock runs inside each profiler span
     events, = lines.values()
-    clocked = ([r.init_seconds, r.batch_seconds[0], r.trace_seconds,
-                r.lower_seconds, r.backend_compile_seconds,
+    clocked = ([r.init_seconds, r.batch_seconds[0], r.lookup_seconds,
+                r.trace_seconds, r.lower_seconds, r.backend_compile_seconds,
                 r.step_seconds[0], r.batch_seconds[1], r.step_seconds[1]])
     for (name, start, end), s in zip(events, clocked):
         assert s * 1e9 <= (end - start) + 1e6, name
@@ -96,13 +130,27 @@ def persistent_cache(tmp_path):
 
 
 def test_cold_cache_misses_then_hits(persistent_cache):
+    train.clear_step_cache()
     cold = train.run_training(ARCH, **TINY)
     assert cold.cache_requests > cold.cache_hits
+    # with the memo emptied the step is compiled again: it reaches the
+    # cache again, and hits
+    train.clear_step_cache()
     warm = train.run_training(ARCH, **TINY)
-    # the step is a fresh jit, so it reaches the cache again, and hits
+    assert not warm.step_reused
     assert warm.cache_requests >= 1
     assert warm.cache_hits == warm.cache_requests
     assert warm.losses == cold.losses
+
+
+def test_second_call_asks_the_cache_nothing(persistent_cache):
+    train.clear_step_cache()
+    first = train.run_training(ARCH, **TINY)
+    second = train.run_training(ARCH, **TINY)
+    assert not first.step_reused and first.cache_requests >= 1
+    assert second.step_reused
+    assert second.cache_requests == second.cache_hits == 0
+    assert second.losses == first.losses
 
 
 def test_cache_counts_are_per_thread(persistent_cache):
