@@ -20,6 +20,7 @@ remainder when ``num_layers`` is not a multiple of the period.
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Mapping
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
@@ -28,7 +29,12 @@ LayerKind = str  # "full" | "local" | "rglru" | "rwkv"
 
 @dataclass(frozen=True)
 class MoECfg:
-    """Mixture-of-experts FFN configuration (DeepSeek-style shared+routed)."""
+    """Mixture-of-experts FFN configuration (DeepSeek-style shared+routed).
+
+    ``num_experts`` is the router's width.  A chip of an expert-parallel
+    deployment may hold a share of them: ``held`` experts from index
+    ``held_from`` (0 holds all ``num_experts``).  The layer then routes
+    over all of them and computes only its held experts' part."""
 
     num_experts: int
     top_k: int
@@ -40,17 +46,25 @@ class MoECfg:
     eval_capacity_factor: float = 2.0
     aux_loss_weight: float = 1e-3
     router_dtype: str = "float32"
+    norm_topk: bool = True       # renormalise the top-k gates to sum to 1
+    held: int = 0                # experts whose weights exist here; 0 -> all
+    held_from: int = 0           # index of the first held expert
 
     @property
     def d_ff_shared(self) -> int:
         return self.num_shared * self.d_ff_expert
 
+    @property
+    def n_held(self) -> int:
+        return self.held or self.num_experts
+
 
 @dataclass(frozen=True)
 class MLACfg:
-    """Multi-head latent attention (DeepSeek-V2)."""
+    """Multi-head latent attention (DeepSeek-V2).  ``q_lora_rank`` 0 or
+    None projects the queries directly, without compression."""
 
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
@@ -127,6 +141,17 @@ class ModelConfig:
     cast_params_bf16: bool = False    # cast f32 masters to bf16 pre-forward
     chunked_ce: bool = False          # never materialize full (B,S,V) logits
 
+    def __post_init__(self):
+        # a configuration read from JSON gives its sub-configurations as
+        # mappings and its pattern as a list: make it the same, hashable
+        # value as one built in code
+        for name, cls in (("moe", MoECfg), ("mla", MLACfg),
+                          ("rglru", RGLRUCfg), ("rwkv", RWKVCfg)):
+            sub = getattr(self, name)
+            if isinstance(sub, Mapping):
+                object.__setattr__(self, name, cls(**sub))
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+
     # ------------------------------------------------------------------
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -174,8 +199,10 @@ class ModelConfig:
 
         if self.mla is not None:
             m = self.mla
-            attn_p = (d * m.q_lora_rank + m.q_lora_rank * H * (m.qk_nope_dim + m.qk_rope_dim)
-                      + d * (m.kv_lora_rank + m.qk_rope_dim)
+            qk = m.qk_nope_dim + m.qk_rope_dim
+            q_p = (d * m.q_lora_rank + m.q_lora_rank * H * qk if m.q_lora_rank
+                   else d * H * qk)
+            attn_p = (q_p + d * (m.kv_lora_rank + m.qk_rope_dim)
                       + m.kv_lora_rank * H * (m.qk_nope_dim + m.v_dim)
                       + H * m.v_dim * d)
         else:
@@ -196,7 +223,7 @@ class ModelConfig:
             router_p = d * mo.num_experts
             n_moe = self.num_layers - mo.first_k_dense
             total += mo.first_k_dense * dense_p
-            total += n_moe * (mo.num_experts * exp_p + shared_p + router_p)
+            total += n_moe * (mo.n_held * exp_p + shared_p + router_p)
 
         if self.rglru is not None:
             g = self.rglru
@@ -214,14 +241,15 @@ class ModelConfig:
         return int(total)
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE: top-k + shared only)."""
+        """Params touched per token (MoE: top-k + shared only, at most
+        the held experts)."""
         if self.moe is None:
             return self.param_count()
         mo = self.moe
         full = self.param_count()
         exp_p = 3 * self.d_model * mo.d_ff_expert
         n_moe = self.num_layers - mo.first_k_dense
-        inactive = n_moe * (mo.num_experts - mo.top_k) * exp_p
+        inactive = n_moe * max(mo.n_held - mo.top_k, 0) * exp_p
         return int(full - inactive)
 
 

@@ -2,8 +2,9 @@
 
 [arXiv:2405.04434; hf] 60L d_model=5120 128H, MLA kv_lora=512
 (q_lora=1536, qk_nope=128, qk_rope=64, v=128), MoE: 2 shared + 160
-routed experts, top-6, expert d_ff=1536, first layer dense (d_ff=12288),
-vocab=102400.
+routed experts, top-6 with the raw softmax scores as gates
+(norm_topk_prob false), expert d_ff=1536, first layer dense
+(d_ff=12288), vocab=102400.
 """
 from repro.configs.base import MLACfg, ModelConfig, MoECfg
 
@@ -25,7 +26,8 @@ CONFIG = ModelConfig(
     mla=MLACfg(q_lora_rank=1536, kv_lora_rank=512,
                qk_nope_dim=128, qk_rope_dim=64, v_dim=128),
     moe=MoECfg(num_experts=160, top_k=6, d_ff_expert=1536,
-               num_shared=2, d_ff_dense=12288, first_k_dense=1),
+               num_shared=2, d_ff_dense=12288, first_k_dense=1,
+               norm_topk=False),    # published norm_topk_prob: false
     param_dtype="bfloat16",
     remat="full",
 )

@@ -74,7 +74,7 @@ def smoke_config(arch_id: str) -> ModelConfig:
         kw["moe"] = MoECfg(num_experts=8, top_k=2, d_ff_expert=32,
                            num_shared=min(cfg.moe.num_shared, 1),
                            d_ff_dense=128, first_k_dense=cfg.moe.first_k_dense,
-                           capacity_factor=2.0)
+                           capacity_factor=2.0, norm_topk=cfg.moe.norm_topk)
     if cfg.mla is not None:
         kw["mla"] = MLACfg(q_lora_rank=32, kv_lora_rank=16,
                            qk_nope_dim=16, qk_rope_dim=8, v_dim=16)

@@ -60,14 +60,14 @@ def base_rules(cfg: ModelConfig, shape: Optional[ShapeCfg],
     }
     if cfg.moe is not None:
         f = cfg.moe.d_ff_expert
-        if cfg.moe.num_experts % max(n_data, 1):
+        if cfg.moe.n_held % max(n_data, 1):
             rules["experts_dp"] = None
         if f % max(n_model, 1):
             rules["expert_tp"] = None
     if cfg.rglru is not None:
         lw = cfg.rglru.lru_width or cfg.d_model
         rules["lru"] = "model" if div(lw, n_model) else None
-    if cfg.moe is not None and not div(cfg.moe.num_experts, n_model):
+    if cfg.moe is not None and not div(cfg.moe.n_held, n_model):
         rules["experts"] = None
     # GQA: sharding q-heads while kv replicated is fine; but if q-heads
     # can't shard, keep kv replicated too (avoids asymmetric layouts).

@@ -40,6 +40,12 @@ running), each on the calling thread and each also timed into the
 ``TrainResult.step_reused`` says whether the call found its step in the
 memo.  It also counts, on the calling thread, the programs it hands to
 XLA through the persistent compilation cache and the cache's hits.
+
+A mixture-of-experts model's step also returns its routing counts
+(``models.moe.COUNTS``, summed over the MoE layers): they go into
+``TrainResult.moe_counts``, one dict per step, and into a process-wide
+tally over every call, read by ``routing_counts``.  The norm of the
+routed experts' gradients goes into ``TrainResult.moe_expert_gnorms``.
 """
 from __future__ import annotations
 
@@ -61,6 +67,7 @@ from repro.configs import get_config, smoke_config
 from repro.data import DataConfig, SyntheticLM
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
+from repro.models import moe as moe_mod
 from repro.models import transformer as tfm
 from repro.optim import AdamWConfig, abstract_opt_state, init_opt_state
 from repro.train.steps import make_train_step
@@ -84,6 +91,11 @@ class TrainResult:
     cache_hits: int = 0               # cache, and those it held
     lookup_seconds: float = 0.0       # the step memo's key and lookup
     step_reused: bool = False         # the step came from the memo
+    # per step, MoE only: pairs routed to held experts, kept, dropped, and
+    # the largest held expert's load, each summed over the MoE layers
+    moe_counts: list = dataclasses.field(default_factory=list)
+    # per step, MoE only: the norm of the routed experts' gradients
+    moe_expert_gnorms: list = dataclasses.field(default_factory=list)
 
 
 # JAX's persistent-cache events, counted per thread: the payloads of a
@@ -192,6 +204,33 @@ def clear_step_cache() -> None:
     _step_memo.clear()
 
 
+class _RoutingTally:
+    """Routing counts summed over every MoE step of the process."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._totals = dict.fromkeys(("steps",) + moe_mod.COUNTS, 0)
+
+    def add(self, counts: Dict[str, int]) -> None:
+        with self._lock:
+            self._totals["steps"] += 1
+            for k in moe_mod.COUNTS:
+                self._totals[k] += counts[k]
+
+    def read(self) -> Dict[str, int]:
+        with self._lock:
+            return dict(self._totals)
+
+
+_routing = _RoutingTally()
+
+
+def routing_counts() -> Dict[str, int]:
+    """MoE steps run so far in this process and their routing counts,
+    summed over steps and layers (``models.moe.COUNTS``)."""
+    return _routing.read()
+
+
 def _signature(tree) -> tuple:
     leaves, treedef = jax.tree.flatten(tree)
     return treedef, tuple((x.shape, x.dtype, x.weak_type, x.sharding)
@@ -278,6 +317,7 @@ def run_training(arch: str, *, smoke: bool = True, steps: int = 50,
     compiled = None
     step_reused = False
     losses = []
+    moe_counts, moe_expert_gnorms = [], []
     tokens = 0
     for step in range(start_step, steps):
         with _span("train.batch", spans):
@@ -293,6 +333,13 @@ def run_training(arch: str, *, smoke: bool = True, steps: int = 50,
             jax.block_until_ready((params, opt_state, metrics))
         loss = float(metrics["loss"])
         losses.append(loss)
+        if cfg.moe is not None:
+            got = jax.device_get({k: metrics[k] for k in
+                                  moe_mod.COUNTS + ("moe_expert_gnorm",)})
+            counts = {k: int(got[k]) for k in moe_mod.COUNTS}
+            moe_counts.append(counts)
+            moe_expert_gnorms.append(float(got["moe_expert_gnorm"]))
+            _routing.add(counts)
         tokens += batch * seq
         if verbose and (step % log_every == 0 or step == steps - 1):
             print(f"step {step:5d} loss {loss:8.4f} "
@@ -330,7 +377,9 @@ def run_training(arch: str, *, smoke: bool = True, steps: int = 50,
                        cache_requests=requests - requests0,
                        cache_hits=hits - hits0,
                        lookup_seconds=sum(spans["train.lookup"]),
-                       step_reused=step_reused)
+                       step_reused=step_reused,
+                       moe_counts=moe_counts,
+                       moe_expert_gnorms=moe_expert_gnorms)
 
 
 def main(argv=None) -> int:

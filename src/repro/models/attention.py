@@ -89,9 +89,11 @@ def blockwise_attention(q, k, v, *, q_pos, k_pos, window=0, cap=0.0,
                         scale=None, block_kv=1024):
     """Online-softmax over KV blocks (flash-style peak memory).
 
-    Wrapped in jax.checkpoint by callers for training so backward
-    recomputes block scores instead of saving per-block probabilities
-    (the FlashAttention backward trade: +1 fwd pass, O(S*D) residuals).
+    The block body is rematerialised: the backward pass keeps only the
+    running max, sum and output of each KV block, O(S*D) per block, and
+    recomputes the block's scores (the FlashAttention backward trade).
+    Without it the scan's backward keeps every block's scores and
+    probabilities, O(S^2) in float32.
     """
     B, Sq, H, D = q.shape
     Sk, K = k.shape[1], k.shape[2]
@@ -128,7 +130,8 @@ def blockwise_attention(q, k, v, *, q_pos, k_pos, window=0, cap=0.0,
     m0 = jnp.full((B, K, G, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, K, G, Sq), jnp.float32)
     a0 = jnp.zeros((B, K, G, Sq, Dv), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), (kb, vb, pb))
+    (m, l, acc), _ = jax.lax.scan(jax.checkpoint(body), (m0, l0, a0),
+                                  (kb, vb, pb))
     o = acc / jnp.maximum(l, 1e-30)[..., None]
     return o.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, Dv).astype(q.dtype)
 

@@ -3,7 +3,9 @@
 Train/prefill use the naive (decompressed) form; decode uses the
 *absorbed* form where W_UK / W_UV are folded into the query / output so
 the KV cache is just the (kv_lora + rope) latent per token — the paper's
-serving-memory contribution.
+serving-memory contribution.  Queries are compressed through a LoRA
+(``wq_a`` -> ``q_norm`` -> ``wq_b``) or, with ``q_lora_rank`` 0 or None
+(DeepSeek-V2-Lite), projected directly by ``wq``.
 """
 from __future__ import annotations
 
@@ -22,10 +24,15 @@ def mla_specs(cfg: ModelConfig) -> dict:
     m = cfg.mla
     d, H = cfg.d_model, cfg.num_heads
     qk = m.qk_nope_dim + m.qk_rope_dim
+    if m.q_lora_rank:
+        q = {"wq_a": ParamSpec((d, m.q_lora_rank), ("embed", "q_lora")),
+             "q_norm": ParamSpec((m.q_lora_rank,), ("q_lora",), init="zeros"),
+             "wq_b": ParamSpec((m.q_lora_rank, H, qk),
+                               ("q_lora", "heads", None))}
+    else:
+        q = {"wq": ParamSpec((d, H, qk), ("embed", "heads", None))}
     return {
-        "wq_a": ParamSpec((d, m.q_lora_rank), ("embed", "q_lora")),
-        "q_norm": ParamSpec((m.q_lora_rank,), ("q_lora",), init="zeros"),
-        "wq_b": ParamSpec((m.q_lora_rank, H, qk), ("q_lora", "heads", None)),
+        **q,
         "wkv_a": ParamSpec((d, m.kv_lora_rank + m.qk_rope_dim),
                            ("embed", "kv_lora")),
         "kv_norm": ParamSpec((m.kv_lora_rank,), ("kv_lora",), init="zeros"),
@@ -58,9 +65,12 @@ def abstract_mla_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
 def _project_q(cfg, p, x):
     m = cfg.mla
     dt = x.dtype
-    cq = x @ p["wq_a"].astype(dt)
-    cq = rms_norm(cq, p["q_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"].astype(dt))
+    if m.q_lora_rank:
+        cq = x @ p["wq_a"].astype(dt)
+        cq = rms_norm(cq, p["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"].astype(dt))
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
     return q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
 
 
@@ -75,6 +85,7 @@ def _project_kv_latent(cfg, p, x, positions):
     return c, kr
 
 
+@jax.named_scope("mla")
 def mla_layer(cfg: ModelConfig, p: dict, x, *, positions, mode: str,
               cache: Optional[dict], mesh=None):
     m = cfg.mla

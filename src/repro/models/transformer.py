@@ -168,10 +168,17 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 # forward
 # ---------------------------------------------------------------------------
 
+def _zero_counts(cfg: ModelConfig) -> dict:
+    """Routing counts of a layer that routes nothing: empty for a model
+    without experts, so that its program carries no counts at all."""
+    return moe_mod.zero_counts() if cfg.moe is not None else {}
+
+
 def _apply_layer(cfg: ModelConfig, kind: str, use_moe: bool, p: dict, x, *,
                  positions, mode: str, cache, mesh):
-    """One residual block. Returns (x, new_cache, aux_loss)."""
+    """One residual block. Returns (x, new_cache, aux_loss, counts)."""
     aux = jnp.zeros((), jnp.float32)
+    counts = _zero_counts(cfg)
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if kind in ("full", "local"):
         if cfg.mla is not None:
@@ -196,14 +203,14 @@ def _apply_layer(cfg: ModelConfig, kind: str, use_moe: bool, p: dict, x, *,
         o, cache = rwkv_mod.rwkv_channel_mix(cfg, p["rwkv_cm"], h, mode=mode,
                                              cache=cache)
     elif use_moe:
-        o, aux = moe_mod.moe_apply(cfg, p["moe"], h, mesh=mesh,
-                                   train=(mode == "train"))
+        o, aux, counts = moe_mod.moe_apply(cfg, p["moe"], h, mesh=mesh,
+                                           train=(mode == "train"))
     else:
         o = mlp_apply(cfg, p["mlp"], h)
     if cfg.sandwich_norm:
         o = rms_norm(o, p["ln2_post"], cfg.norm_eps)
     x = x + o
-    return x, cache, aux
+    return x, cache, aux, counts
 
 
 def _remat_wrap(cfg: ModelConfig, fn, mode: str):
@@ -216,9 +223,12 @@ def _remat_wrap(cfg: ModelConfig, fn, mode: str):
 
 
 def forward(cfg: ModelConfig, params, batch: Dict[str, Any], *, mode: str,
-            cache=None, mesh=None, return_hidden: bool = False):
+            cache=None, mesh=None, return_hidden: bool = False,
+            with_counts: bool = False):
     """Returns (logits, new_cache, aux_loss) — or (hidden, cache, aux) when
-    ``return_hidden`` (the chunked-CE path computes logits itself).
+    ``return_hidden`` (the chunked-CE path computes logits itself); with
+    ``with_counts``, the step's routing counts summed over the MoE layers
+    (``moe.COUNTS``; empty without experts) come fourth.
 
     batch: {"tokens": (B,S) int32} or {"embeds": (B,S,d)};
     decode mode uses cache["pos"] for positions.
@@ -245,7 +255,11 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, Any], *, mode: str,
                                      (B, S))
 
     aux_total = jnp.zeros((), jnp.float32)
+    counts_total = _zero_counts(cfg)
     new_cache = None if cache is None else dict(cache)
+
+    def add(a, b):
+        return jax.tree.map(jnp.add, a, b)
 
     def run_layer(i_global, kind, p, x, c):
         return _apply_layer(cfg, kind, cfg.layer_uses_moe(i_global), p, x,
@@ -255,8 +269,9 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, Any], *, mode: str,
     pro_caches = []
     for i, k in enumerate(pro):
         c = None if cache is None else cache["prologue"][i]
-        x, c, aux = run_layer(i, k, params["prologue"][i], x, c)
+        x, c, aux, counts = run_layer(i, k, params["prologue"][i], x, c)
         aux_total += aux
+        counts_total = add(counts_total, counts)
         pro_caches.append(c)
 
     # scanned periods
@@ -265,29 +280,34 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, Any], *, mode: str,
         base = len(pro)
 
         def period_body(carry, xs):
-            x, aux_acc = carry
+            x, aux_acc, counts_acc = carry
             x = _constrain(x, mesh, spec_dims=False,
                            seq_shard=cfg.seq_shard)
             p_list, c_list = xs
             new_c = []
             for j, kind in enumerate(cfg.layer_pattern):
                 cj = None if c_list is None else c_list[j]
-                x, cj, aux = run_layer(base + j, kind, p_list[j], x, cj)
+                x, cj, aux, counts = run_layer(base + j, kind, p_list[j], x,
+                                               cj)
                 aux_acc = aux_acc + aux
+                counts_acc = add(counts_acc, counts)
                 new_c.append(cj)
-            return (x, aux_acc), new_c
+            return (x, aux_acc, counts_acc), new_c
 
         body = _remat_wrap(cfg, period_body, mode)
         xs = (params["scan"], scan_caches)
-        (x, aux_total), scan_caches = jax.lax.scan(body, (x, aux_total), xs)
+        (x, aux_total, counts_total), scan_caches = jax.lax.scan(
+            body, (x, aux_total, counts_total), xs)
 
     # epilogue
     epi_caches = []
     epi_base = len(pro) + n_periods * cfg.period
     for i, k in enumerate(epi):
         c = None if cache is None else cache["epilogue"][i]
-        x, c, aux = run_layer(epi_base + i, k, params["epilogue"][i], x, c)
+        x, c, aux, counts = run_layer(epi_base + i, k, params["epilogue"][i],
+                                      x, c)
         aux_total += aux
+        counts_total = add(counts_total, counts)
         epi_caches.append(c)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -299,7 +319,8 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, Any], *, mode: str,
             new_cache["epilogue"] = epi_caches
             step = jnp.asarray(1 if mode == "decode" else S, jnp.int32)
             new_cache["pos"] = cache["pos"] + step
-        return x, new_cache, aux_total
+        return ((x, new_cache, aux_total, counts_total) if with_counts
+                else (x, new_cache, aux_total))
     if cfg.tie_embeddings and cfg.input_kind == "tokens":
         logits = jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(dt))
     else:
@@ -314,4 +335,6 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, Any], *, mode: str,
         new_cache["epilogue"] = epi_caches
         step = jnp.asarray(1 if mode == "decode" else S, jnp.int32)
         new_cache["pos"] = cache["pos"] + step
+    if with_counts:
+        return logits, new_cache, aux_total, counts_total
     return logits, new_cache, aux_total

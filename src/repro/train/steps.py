@@ -16,9 +16,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeCfg
 from repro.distributed import sharding as shd
+from repro.models import moe as moe_mod
 from repro.models import transformer as tfm
 from repro.optim import (AdamWConfig, OptState, abstract_opt_state,
-                         apply_updates, init_opt_state, linear_warmup_cosine)
+                         apply_updates, global_norm, init_opt_state,
+                         linear_warmup_cosine)
 
 
 # ---------------------------------------------------------------------------
@@ -90,19 +92,23 @@ def compute_params(cfg: ModelConfig, params):
 
 
 def loss_fn(cfg: ModelConfig, params, batch, *, mesh=None):
+    """Returns (loss, metrics); an MoE model's metrics carry the step's
+    routing counts (``moe.COUNTS``), a dense model's none."""
     fwd_params = compute_params(cfg, params)
     labels = batch["labels"]
     if cfg.chunked_ce:
-        hidden, _, aux = tfm.forward(cfg, fwd_params, batch, mode="train",
-                                     mesh=mesh, return_hidden=True)
+        hidden, _, aux, counts = tfm.forward(
+            cfg, fwd_params, batch, mode="train", mesh=mesh,
+            return_hidden=True, with_counts=True)
         loss = chunked_cross_entropy(cfg, fwd_params, hidden, labels)
     else:
-        logits, _, aux = tfm.forward(cfg, fwd_params, batch, mode="train",
-                                     mesh=mesh)
+        logits, _, aux, counts = tfm.forward(
+            cfg, fwd_params, batch, mode="train", mesh=mesh,
+            with_counts=True)
         loss = cross_entropy(logits, labels)
     if cfg.moe is not None:
         loss = loss + cfg.moe.aux_loss_weight * aux
-    return loss, {"loss": loss, "aux": aux}
+    return loss, {"loss": loss, "aux": aux, **counts}
 
 
 # ---------------------------------------------------------------------------
@@ -146,6 +152,10 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig, *, mesh=None,
                                               opt_cfg, lr_scale)
         metrics = dict(metrics)
         metrics.update(om)
+        if cfg.moe is not None:
+            # the routed experts' part of the gradient, before clipping
+            metrics["moe_expert_gnorm"] = global_norm(
+                moe_mod.expert_leaves(grads))
         return params, opt_state, metrics
     return train_step
 

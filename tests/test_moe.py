@@ -29,8 +29,8 @@ def test_ep_matches_dense_oracle_when_no_drops(local_mesh):
     cfg = _cfg(cf=8.0)   # capacity high enough that nothing drops
     p = _params(cfg, KEY)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
-    y_dense, aux_d = moe_mod.moe_dense(cfg, p, x)
-    y_ep, aux_e = moe_mod.moe_ep(cfg, p, x, mesh=local_mesh, train=True)
+    y_dense, aux_d, _ = moe_mod.moe_dense(cfg, p, x)
+    y_ep, aux_e, _ = moe_mod.moe_ep(cfg, p, x, mesh=local_mesh, train=True)
     np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_dense),
                                atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(float(aux_e), float(aux_d), rtol=1e-5)
@@ -42,11 +42,11 @@ def test_ep_gradients_match_dense(local_mesh):
     x = jax.random.normal(jax.random.PRNGKey(2), (1, 8, cfg.d_model))
 
     def loss_dense(p_):
-        y, aux = moe_mod.moe_dense(cfg, p_, x)
+        y, aux, _ = moe_mod.moe_dense(cfg, p_, x)
         return jnp.sum(y ** 2) + aux
 
     def loss_ep(p_):
-        y, aux = moe_mod.moe_ep(cfg, p_, x, mesh=local_mesh, train=True)
+        y, aux, _ = moe_mod.moe_ep(cfg, p_, x, mesh=local_mesh, train=True)
         return jnp.sum(y ** 2) + aux
 
     gd = jax.grad(loss_dense)(p)
@@ -65,8 +65,8 @@ def test_capacity_drops_zero_out_overflow(local_mesh):
     cfg_hi = _cfg(cf=8.0)
     p = _params(cfg_hi, KEY)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 256, cfg_hi.d_model))
-    y_lo, _ = moe_mod.moe_ep(cfg_lo, p, x, mesh=local_mesh, train=True)
-    y_hi, _ = moe_mod.moe_ep(cfg_hi, p, x, mesh=local_mesh, train=True)
+    y_lo, _, _ = moe_mod.moe_ep(cfg_lo, p, x, mesh=local_mesh, train=True)
+    y_hi, _, _ = moe_mod.moe_ep(cfg_hi, p, x, mesh=local_mesh, train=True)
     assert np.isfinite(np.asarray(y_lo)).all()
     assert float(jnp.linalg.norm(y_lo)) < float(jnp.linalg.norm(y_hi))
 
